@@ -36,6 +36,13 @@ the provisioned resource:
   ``lax.fori_loop`` of exactly ``decode_chunk`` on-device steps (a static
   bound: one compile, ever) with the pool donated to each chunk; tokens
   accumulate on device and cross to the host once per chunk.
+- **Every phase is a span and a counter.** An admission wave (plan /
+  prefill / sync) and a decode chunk (prep / dispatch / sync / post) each
+  open a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``, on the
+  profiler's clock beside the device's trace, and add their host seconds
+  to ``stats``, beside counts of the decode slot-steps and prefill rows
+  that did useful work and each request's wait in the queue. A
+  registry-bound engine exports them as ``kotta_engine_<key>_total``.
 - **Speculative multi-token decode** (``enable_spec_decode``): each on-device
   step drafts ``spec_tokens`` candidates per slot by bigram prompt-lookup
   over the slot's own token history (kept on device in the chunk carry),
@@ -98,6 +105,7 @@ import enum
 import math
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -508,8 +516,10 @@ class ContinuousBatchingEngine:
         self.demoted_out: list[ShippedKV] = []
         # Admission queue, consumed front-first by ``admit``. The caller
         # controls its order: ``generate`` fills it FCFS, the gateway keeps
-        # it policy-ordered (EDF within priority class).
-        self._queue: deque[EngineRequest] = deque()
+        # it policy-ordered (EDF within priority class). Each request sits
+        # beside its enqueue stamp (``time.perf_counter``), which admission
+        # turns into ``queue_wait_seconds``.
+        self._queue: deque[tuple[EngineRequest, float]] = deque()
         self.stats: dict[str, float] = {}
         self._reset_stats()
 
@@ -675,19 +685,31 @@ class ContinuousBatchingEngine:
         self._ship_scatter_cache = {}
 
     # -- stats ---------------------------------------------------------------
+    # Phase counters: each ``*_seconds`` key is the host time of one engine
+    # phase, also written as a profiler span by ``_span``; the step and row
+    # counts beside them say how much of each dispatch did useful work.
+    _PHASE_ZEROS = {"admit_waves": 0, "admit_plan_seconds": 0.0,
+                    "admit_dispatch_seconds": 0.0, "admit_sync_seconds": 0.0,
+                    "queue_wait_seconds": 0.0, "prefill_steps": 0,
+                    "prefill_slot_tokens": 0, "decode_chunks": 0,
+                    "decode_seconds": 0.0, "decode_prep_seconds": 0.0,
+                    "decode_dispatch_seconds": 0.0,
+                    "decode_sync_seconds": 0.0, "decode_post_seconds": 0.0,
+                    "decode_slot_steps": 0, "decode_live_steps": 0}
     _STAT_ZEROS = {"admitted": 0, "prefill_tokens": 0, "cached_tokens": 0,
                    "cow_copies": 0, "admit_seconds": 0.0,
                    "spec_steps": 0, "spec_emitted": 0,
                    "preempted": 0, "resumed": 0,
                    "page_exports": 0, "page_imports": 0,
                    "page_demotes": 0, "page_restores": 0,
-                   "accept_ema_sum": 0.0, "accept_ema_n": 0}
+                   "accept_ema_sum": 0.0, "accept_ema_n": 0, **_PHASE_ZEROS}
     # Keys exported when bound to a MetricsRegistry; the scratch
-    # accumulators (admit_seconds, accept EMA terms) stay local-only.
+    # accumulators (accept EMA terms) stay local-only.
     _STAT_EXPORTED = ("admitted", "prefill_tokens", "cached_tokens",
-                      "cow_copies", "spec_steps", "spec_emitted",
+                      "cow_copies", "admit_seconds", "spec_steps",
+                      "spec_emitted",
                       "preempted", "resumed", "page_exports", "page_imports",
-                      "page_demotes", "page_restores")
+                      "page_demotes", "page_restores", *_PHASE_ZEROS)
 
     def _reset_stats(self):
         stats = getattr(self, "stats", None)
@@ -714,6 +736,17 @@ class ContinuousBatchingEngine:
             if key not in self._STAT_EXPORTED:
                 rd.bind(key, None, initial=self.stats[key])
         self.stats = rd
+
+    @contextmanager
+    def _span(self, name: str, key: str, **args):
+        """One engine phase: a profiler span ``name`` (about a microsecond
+        when no profiler runs) whose host seconds also add to
+        ``stats[key]``. Yields the span, for metadata known only at its
+        end (``set_metadata``)."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(name, **args) as span:
+            yield span
+        self.stats[key] += time.perf_counter() - t0
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -788,14 +821,49 @@ class ContinuousBatchingEngine:
         the donor, each group is one batched prefill, and a group's
         copy-on-write boundary copies are dispatched after its donors'
         group has prefilled but before its own prefill reads them.
+
+        A wave is three phases (:meth:`_span`): ``plan`` (the queue loop,
+        host only), ``prefill`` (every group's copies and chunk steps,
+        dispatched without waiting) and ``sync`` (the first tokens read
+        back, the wave's only wait on the device).
         """
-        t0 = time.perf_counter()
+        with self._span("engine.admit", "admit_seconds") as span:
+            with self._span("engine.admit.plan", "admit_plan_seconds"):
+                wave, cow_pairs = self._plan_wave()
+            if wave:
+                with self._span("engine.admit.prefill",
+                                "admit_dispatch_seconds"):
+                    firsts = []
+                    for g in sorted({a.group for a in wave}):
+                        self._dispatch_cows(cow_pairs.get(g, ()))
+                        members = [a for a in wave if a.group == g]
+                        if self.prefill_mode == "dense":
+                            firsts += self._prefill_dense(members)
+                        else:
+                            firsts += self._prefill_paged_wave(members)
+                    if self.spec_decode:
+                        self._load_histories(wave)
+                with self._span("engine.admit.sync", "admit_sync_seconds"):
+                    self._take_first_tokens(firsts)
+                    for a in wave:
+                        self._live[a.slot] = _Live(a.req, a.pages)
+                self.stats["admitted"] += len(wave)
+                self.stats["admit_waves"] += 1
+            span.set_metadata(requests=len(wave))
+        return len(wave)
+
+    def _plan_wave(self):
+        """The queue loop of :meth:`_admit_wave`: take requests front-first
+        while a slot and their pages last, reserve both, and add each one's
+        wait from ``enqueue`` to this wave's start to ``queue_wait_seconds``.
+        Returns the wave and its copy-on-write pairs by prefill group."""
+        t_wave = time.perf_counter()
         ps = self.page_size
         wave: list[_Admit] = []
         cow_pairs: dict[int, list[tuple[int, int]]] = {}   # group -> pairs
         page_group: dict[int, int] = {}    # page -> group whose prefill fills it
         while self._queue:
-            req = self._queue[0]
+            req, t_enq = self._queue[0]
             prompt = req.prompt
             plen = len(prompt)
             free_slots = [i for i in range(self.max_slots)
@@ -856,22 +924,8 @@ class ContinuousBatchingEngine:
                 # prefill below guarantees the content lands first.
                 self.prefix_cache.register(prompt, pages, req.namespace)
             self._queue.popleft()
-
-        if wave:
-            for g in sorted({a.group for a in wave}):
-                self._dispatch_cows(cow_pairs.get(g, ()))
-                members = [a for a in wave if a.group == g]
-                if self.prefill_mode == "dense":
-                    self._prefill_dense(members)
-                else:
-                    self._prefill_paged_wave(members)
-            for a in wave:
-                self._live[a.slot] = _Live(a.req, a.pages)
-            if self.spec_decode:
-                self._load_histories(wave)
-            self.stats["admitted"] += len(wave)
-        self.stats["admit_seconds"] += time.perf_counter() - t0
-        return len(wave)
+            self.stats["queue_wait_seconds"] += t_wave - t_enq
+        return wave, cow_pairs
 
     def _dispatch_cows(self, cow_pairs) -> None:
         """One device dispatch copies a prefill group's boundary pages,
@@ -886,6 +940,17 @@ class ContinuousBatchingEngine:
         self.pool = self._cow(self.pool, jnp.asarray(src), jnp.asarray(dst))
         for s_, _ in cow_pairs:
             self.alloc.release(s_)              # pin no longer needed
+
+    def _take_first_tokens(self, firsts) -> None:
+        """Read each admitted request's first token back from its prefill
+        output, ``firsts`` = [(slot, device array, row, prompt length)]:
+        one host read per distinct array."""
+        host: dict[int, np.ndarray] = {}
+        for slot, arr, row, plen in firsts:
+            if id(arr) not in host:
+                host[id(arr)] = np.asarray(arr)
+            self._cur[slot] = host[id(arr)][row]
+            self._pos[slot] = plen
 
     def _load_histories(self, wave: list[_Admit]) -> None:
         """Seed the on-device drafting history + write limit for new slots."""
@@ -902,13 +967,14 @@ class ContinuousBatchingEngine:
         self._hist = self._hist.at[jnp.asarray(slots)].set(jnp.asarray(rows))
 
     # -- paged chunked prefill (default admission path) ----------------------
-    def _prefill_paged_wave(self, wave: list[_Admit]) -> None:
+    def _prefill_paged_wave(self, wave: list[_Admit]) -> list:
         """Prefill every wave member's suffix in fixed-width chunk steps.
 
         The batch is padded to a power-of-two bucket so the jitted step sees
         a handful of (bucket, chunk) signatures total — never one per prompt
         length. Pad rows carry ``kv_len=0`` so all their KV writes land in
-        the sink page.
+        the sink page. Nothing waits for the device: returns, per member,
+        where its first token lies (see :meth:`_take_first_tokens`).
         """
         ps, c = self.page_size, self.prefill_chunk
         gp = _next_pow2(len(wave))
@@ -940,21 +1006,21 @@ class ContinuousBatchingEngine:
             nxt, _, self.pool = self._prefill_chunked(self.params, batch,
                                                       self.pool)
             step_toks.append(nxt)
+        self.stats["prefill_steps"] += nsteps
+        self.stats["prefill_slot_tokens"] += nsteps * gp * c
 
         # The first sampled token of request i comes from the chunk holding
-        # position plen-1; sync each needed step array once.
-        host: dict[int, np.ndarray] = {}
-        for i, a in enumerate(wave):
-            j = (len(a.req.prompt) - 1 - a.start) // c
-            if j not in host:
-                host[j] = np.asarray(step_toks[j])
-            self._cur[a.slot] = host[j][i]
-            self._pos[a.slot] = len(a.req.prompt)
+        # position plen-1.
+        return [(a.slot, step_toks[(len(a.req.prompt) - 1 - a.start) // c],
+                 i, len(a.req.prompt)) for i, a in enumerate(wave)]
 
     # -- dense ragged prefill (PR-1 baseline, kept as in-engine oracle) ------
-    def _prefill_dense(self, wave: list[_Admit]) -> None:
-        """Batched-by-pad-bucket dense prefill + page re-layout (legacy)."""
+    def _prefill_dense(self, wave: list[_Admit]) -> list:
+        """Batched-by-pad-bucket dense prefill + page re-layout (legacy);
+        returns where each member's first token lies, like
+        :meth:`_prefill_paged_wave`."""
         ps = self.page_size
+        firsts = []
         by_pad: dict[int, list[_Admit]] = {}
         for a in wave:
             s_pad = math.ceil(len(a.req.prompt) / ps) * ps
@@ -974,10 +1040,12 @@ class ContinuousBatchingEngine:
             prompt_pages = np.concatenate(
                 [np.asarray(a.pages[:npp], np.int32) for a in items])
             self._write_pages(cache["k"], cache["v"], prompt_pages)
-            first = np.array(jnp.argmax(logits, axis=-1), np.int32)  # 1 sync
-            for i, a in enumerate(items):
-                self._pos[a.slot] = len(a.req.prompt)
-                self._cur[a.slot] = first[i]
+            self.stats["prefill_steps"] += 1
+            self.stats["prefill_slot_tokens"] += g * s_pad
+            first = jnp.argmax(logits, axis=-1)
+            firsts += [(a.slot, first, i, len(a.req.prompt))
+                       for i, a in enumerate(items)]
+        return firsts
 
     def _retire(self, slot: int) -> _Live:
         live = self._live.pop(slot)
@@ -1036,7 +1104,7 @@ class ContinuousBatchingEngine:
     def enqueue(self, req: EngineRequest) -> None:
         """Append a validated request to the admission queue."""
         self._validate_request(req)
-        self._queue.append(req)
+        self._queue.append((req, time.perf_counter()))
 
     def admit(self) -> int:
         """Run one admission wave off the queue; returns requests admitted."""
@@ -1432,7 +1500,7 @@ class ContinuousBatchingEngine:
     def drop_queued(self) -> list[EngineRequest]:
         """Hand back queued-but-unadmitted requests (e.g. transient page
         pressure); live and paused requests are untouched."""
-        dropped = list(self._queue)
+        dropped = [req for req, _ in self._queue]
         self._queue.clear()
         return dropped
 
@@ -1454,7 +1522,7 @@ class ContinuousBatchingEngine:
                 self.alloc.release(p)
             dropped.append(paused.req)
         self._paused.clear()
-        dropped.extend(self._queue)
+        dropped.extend(req for req, _ in self._queue)
         self._queue.clear()
         return dropped
 
@@ -1470,6 +1538,13 @@ class ContinuousBatchingEngine:
         page) for the remaining steps. Under speculative decode one step
         emits 1..spec_tokens+1 tokens per slot, so ``seconds / steps`` is
         per-VERIFY-step latency there.
+
+        A chunk is four phases (:meth:`_span`): ``prep`` (budgets, bucket
+        choice, inputs to the device), ``dispatch`` (the jitted chunk call),
+        ``sync`` (the chunk's outputs read back, its only wait on the
+        device) and ``post`` (tokens to their requests, retirement).
+        ``seconds`` runs from after the budgets are built to the end of
+        ``sync``.
         """
         if self.role == "prefill":
             raise RuntimeError("decode_step on a prefill-role engine: "
@@ -1477,72 +1552,107 @@ class ContinuousBatchingEngine:
                                "decode-role replica instead")
         if not self._live:
             return []
-        budget = np.zeros(self.max_slots, np.int32)
-        for slot, live in self._live.items():
-            budget[slot] = live.req.max_new - live.emitted
-        t0 = time.perf_counter()
-        if self.spec_decode:
-            # Smallest verify bucket covering every live slot's adaptive
-            # window: a chunk full of low-acceptance slots dispatches a
-            # genuinely narrower verify pass. Chunks are jitted lazily per
-            # bucket; non-adaptive engines always land on bucket K.
-            kslot = np.maximum(np.where(self._active, self._kslot, 1), 1)
-            kmax = int(kslot[self._active].max())
-            kb = min(b for b in self._spec_buckets if b >= kmax)
-            chunk = self._spec_chunks.get(kb)
-            if chunk is None:
-                chunk = self._spec_chunks[kb] = self._make_spec_chunk(kb)
-            cur, pos, self._hist, n_out, n_it, self.pool, out = chunk(
-                self.params, jnp.asarray(self._cur),
-                jnp.asarray(self._pos), self._hist,
-                jnp.asarray(self._page_table),
-                jnp.asarray(self._active), jnp.asarray(budget),
-                jnp.asarray(self._limit),
-                jnp.asarray(kslot.astype(np.int32)), self.pool)
-            n_out_host = np.asarray(n_out)
-            n_it_host = np.asarray(n_it)
-            self.stats["spec_steps"] += int(n_it_host.sum())
-            # -- per-slot accept-rate EMA + adaptive window control -------
-            # rate = accepted drafts / drafted tokens this chunk; EMA with
-            # alpha=0.5 reacts within a couple of chunks. High acceptance
-            # re-opens the window (x2, capped at K), low acceptance halves
-            # it (floor 1) so near-random content stops paying for K-token
-            # verify rows it never accepts.
-            for slot in self._live:
-                it = int(n_it_host[slot])
-                if not it:
-                    continue
-                rate = (int(n_out_host[slot]) - it) / (it * int(kslot[slot]))
-                self._ema[slot] = 0.5 * self._ema[slot] + 0.5 * rate
-                if self.spec_adaptive_k:
-                    if self._ema[slot] > 0.8:
-                        self._kslot[slot] = min(2 * int(self._kslot[slot]),
-                                                self.spec_tokens)
-                    elif self._ema[slot] < 0.3:
-                        self._kslot[slot] = max(int(self._kslot[slot]) // 2,
-                                                1)
-        else:
-            cur, pos, self.pool, out = self._chunk(
-                self.params, jnp.asarray(self._cur),
-                jnp.asarray(self._pos), jnp.asarray(self._page_table),
-                jnp.asarray(self._active), jnp.asarray(budget), self.pool)
-            n_out_host = None              # every live slot emits the chunk
-        out_host = np.asarray(out)                      # one sync per chunk
-        if on_chunk is not None:
-            on_chunk(self.decode_chunk, time.perf_counter() - t0)
+        with self._span("engine.decode", "decode_seconds",
+                        live=len(self._live)):
+            with self._span("engine.decode.prep", "decode_prep_seconds"):
+                budget = np.zeros(self.max_slots, np.int32)
+                for slot, live in self._live.items():
+                    budget[slot] = live.req.max_new - live.emitted
+                t0 = time.perf_counter()
+                if self.spec_decode:
+                    # Smallest verify bucket covering every live slot's
+                    # adaptive window: a chunk full of low-acceptance slots
+                    # dispatches a genuinely narrower verify pass. Chunks are
+                    # jitted lazily per bucket; non-adaptive engines always
+                    # land on bucket K.
+                    kslot = np.maximum(np.where(self._active, self._kslot,
+                                                1), 1)
+                    kmax = int(kslot[self._active].max())
+                    kb = min(b for b in self._spec_buckets if b >= kmax)
+                    chunk = self._spec_chunks.get(kb)
+                    if chunk is None:
+                        chunk = self._spec_chunks[kb] = \
+                            self._make_spec_chunk(kb)
+                    args = (jnp.asarray(self._cur), jnp.asarray(self._pos),
+                            self._hist, jnp.asarray(self._page_table),
+                            jnp.asarray(self._active), jnp.asarray(budget),
+                            jnp.asarray(self._limit),
+                            jnp.asarray(kslot.astype(np.int32)))
+                else:
+                    chunk = self._chunk
+                    args = (jnp.asarray(self._cur), jnp.asarray(self._pos),
+                            jnp.asarray(self._page_table),
+                            jnp.asarray(self._active), jnp.asarray(budget))
+            with self._span("engine.decode.dispatch",
+                            "decode_dispatch_seconds"):
+                if self.spec_decode:
+                    cur, pos, self._hist, n_out, n_it, self.pool, out = \
+                        chunk(self.params, *args, self.pool)
+                else:
+                    cur, pos, self.pool, out = chunk(self.params, *args,
+                                                     self.pool)
+            with self._span("engine.decode.sync", "decode_sync_seconds"):
+                out_host = np.asarray(out)              # one sync per chunk
+                if self.spec_decode:
+                    n_out_host = np.asarray(n_out)
+                    n_it_host = np.asarray(n_it)
+                else:                      # every live slot emits the chunk
+                    n_out_host = n_it_host = None
+            if on_chunk is not None:
+                on_chunk(self.decode_chunk, time.perf_counter() - t0)
+            with self._span("engine.decode.post", "decode_post_seconds"):
+                if self.spec_decode:
+                    self._adapt_windows(kslot, n_out_host, n_it_host)
+                finished = self._take_chunk(cur, pos, out_host, n_out_host,
+                                            n_it_host)
+            self.stats["decode_chunks"] += 1
+            self.stats["decode_slot_steps"] += \
+                self.max_slots * self.decode_chunk
+        return finished
+
+    def _adapt_windows(self, kslot, n_out_host, n_it_host) -> None:
+        """Per-slot accept-rate EMA and adaptive window control after a
+        speculative chunk. rate = accepted drafts / drafted tokens this
+        chunk; EMA with alpha=0.5 reacts within a couple of chunks. High
+        acceptance re-opens the window (x2, capped at K), low acceptance
+        halves it (floor 1) so near-random content stops paying for K-token
+        verify rows it never accepts."""
+        self.stats["spec_steps"] += int(n_it_host.sum())
+        for slot in self._live:
+            it = int(n_it_host[slot])
+            if not it:
+                continue
+            rate = (int(n_out_host[slot]) - it) / (it * int(kslot[slot]))
+            self._ema[slot] = 0.5 * self._ema[slot] + 0.5 * rate
+            if self.spec_adaptive_k:
+                if self._ema[slot] > 0.8:
+                    self._kslot[slot] = min(2 * int(self._kslot[slot]),
+                                            self.spec_tokens)
+                elif self._ema[slot] < 0.3:
+                    self._kslot[slot] = max(int(self._kslot[slot]) // 2, 1)
+
+    def _take_chunk(self, cur, pos, out_host, n_out_host, n_it_host
+                    ) -> list[tuple[EngineRequest, list[int]]]:
+        """Hand a finished chunk's tokens to their requests, count the
+        steps each live slot did useful work (``decode_live_steps``), and
+        retire (or demote) the requests whose budget is spent."""
         self._cur = np.array(cur)          # np.array: writable host copies
         self._pos = np.array(pos)
         finished: list[tuple[EngineRequest, list[int]]] = []
         for slot in list(self._live):
             live = self._live[slot]
-            ntok = self.decode_chunk if n_out_host is None \
-                else int(n_out_host[slot])
-            if n_out_host is not None:
+            left = live.req.max_new - live.emitted
+            if n_out_host is None:
+                ntok = self.decode_chunk
+                # Live for min(chunk, budget) steps, then masked.
+                self.stats["decode_live_steps"] += min(ntok, left)
+            else:
+                ntok = int(n_out_host[slot])
+                self.stats["decode_live_steps"] += int(n_it_host[slot])
                 # Count only delivered tokens: the final verify step can
                 # overshoot the budget and its truncated tail must not
                 # inflate mean_accepted_len.
-                self.stats["spec_emitted"] += min(
-                    ntok, live.req.max_new - live.emitted)
+                self.stats["spec_emitted"] += min(ntok, left)
             live.tokens.extend(out_host[slot, :ntok].tolist())
             live.emitted += ntok
             if live.emitted >= live.req.max_new:
@@ -1578,7 +1688,8 @@ class ContinuousBatchingEngine:
         for r in reqs:                        # validate before reserving
             self._validate_request(r)
         self._reset_stats()
-        self._queue.extend(reqs)
+        now = time.perf_counter()
+        self._queue.extend((r, now) for r in reqs)
         done: dict[object, list[int]] = {}
         self._admit_wave()
         if self._queue and not self._live:

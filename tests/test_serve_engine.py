@@ -2,8 +2,12 @@
 copy-on-write prefix sharing, same-wave prefix dedup, refcount invariants,
 page reuse across retire/readmit, eviction-on-realloc, exhaustion mid-wave,
 up-front capacity validation, speculative decode token-identity, lossless
-decode preemption (pause/resume with pinned pages and zero re-prefill), and
-the one-compile guarantees for the decode/verify/prefill hot paths."""
+decode preemption (pause/resume with pinned pages and zero re-prefill), the
+one-compile guarantees for the decode/verify/prefill hot paths, and the
+engine's phase spans and counters."""
+import glob
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -843,3 +847,103 @@ def test_prefix_cache_eviction_on_realloc_under_namespace_churn():
     assert al.alloc() == pc2
     assert pc.lookup(prompt, namespace="tenant-c") == ([], 0)
     assert pc.lookup(prompt, namespace="tenant-b") == (pb, 8)
+
+
+# ---------------------------------------------------------------------------
+# Phase spans and counters
+# ---------------------------------------------------------------------------
+
+ADMIT_PHASES = ("admit_plan_seconds", "admit_dispatch_seconds",
+                "admit_sync_seconds")
+DECODE_PHASES = ("decode_prep_seconds", "decode_dispatch_seconds",
+                 "decode_sync_seconds", "decode_post_seconds")
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_phase_counters_by_hand_count(model, spec):
+    """Two slots, chunks of 4 steps, prefill chunks of 8. Wave 1 admits A
+    (prompt 5, 3 tokens) and B (prompt 11, 6 tokens); C (prompt 7, 2
+    tokens) waits behind the full engine through chunk 1, where A's budget
+    ends after 3 steps. Wave 2 admits C; B and C both end inside chunk 2
+    (2 live steps each)."""
+    cfg, params = model
+    eng = ContinuousBatchingEngine(cfg, params, max_len=64, max_slots=2,
+                                   prefill_chunk=8, decode_chunk=4,
+                                   enable_spec_decode=spec, spec_tokens=3)
+    pa, pb, pc = _prompts(cfg.vocab_size, [5, 11, 7], seed=41)
+    t_enq = time.perf_counter()
+    for rid, (p, m) in enumerate([(pa, 3), (pb, 6), (pc, 2)]):
+        eng.enqueue(EngineRequest(rid, p, m))
+    assert eng.admit() == 2
+    t_wave1 = time.perf_counter()
+    st = eng.stats
+    wait1, admit1 = st["queue_wait_seconds"], st["admit_seconds"]
+    # A and B were admitted by the wave that followed their enqueue.
+    assert 0 < wait1 <= 2 * (t_wave1 - t_enq)
+    eng.decode_step()
+    decode1 = st["decode_seconds"]
+    assert eng.admit() == 1
+    # C waited through wave 1 and chunk 1, and nothing else waited.
+    assert st["queue_wait_seconds"] - wait1 >= admit1 + decode1
+    while eng.has_work:
+        eng.decode_step()
+        eng.admit()
+
+    assert st["admitted"] == 3 and st["admit_waves"] == 2
+    assert st["decode_chunks"] == 2
+    assert st["decode_slot_steps"] == 2 * 2 * 4
+    # Wave 1: a bucket of 2 rows, B's 11 tokens take 2 chunk steps; wave 2:
+    # a bucket of 1 row, 1 step.
+    assert st["prefill_steps"] == 3
+    assert st["prefill_slot_tokens"] == 2 * 2 * 8 + 1 * 1 * 8
+    assert st["prefill_tokens"] == 5 + 11 + 7 and st["cached_tokens"] == 0
+    if spec:
+        # A verify step is live until the slot's budget is emitted, so the
+        # live steps are the verify steps, at most the plain count.
+        assert st["decode_live_steps"] == st["spec_steps"]
+        assert 3 <= st["decode_live_steps"] <= 11
+    else:
+        assert st["decode_live_steps"] == (3 + 4) + (2 + 2)
+    assert sum(st[k] for k in ADMIT_PHASES) <= st["admit_seconds"]
+    assert sum(st[k] for k in DECODE_PHASES) <= st["decode_seconds"]
+    assert sum(st[k] for k in DECODE_PHASES) >= 0.9 * st["decode_seconds"]
+
+
+def _events(trace_dir):
+    from jax.profiler import ProfileData
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns))
+    return out
+
+
+def test_phase_spans_reach_the_profiler(model, tmp_path):
+    """One admission wave and one decode chunk under a CPU profiler trace:
+    every phase span is in the trace, each inside its parent."""
+    cfg, params = model
+    eng = ContinuousBatchingEngine(cfg, params, max_len=64, max_slots=2,
+                                   prefill_chunk=8, decode_chunk=4)
+    for rid, n in enumerate([5, 11]):
+        eng.enqueue(EngineRequest(
+            rid, _prompts(cfg.vocab_size, [n], seed=42 + rid)[0], 8))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.admit()
+        eng.decode_step()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _events(tmp_path)
+    children = {"engine.admit": ("plan", "prefill", "sync"),
+                "engine.decode": ("prep", "dispatch", "sync", "post")}
+    assert set(ev) == set(children) | {f"{p}.{c}" for p, cs in
+                                       children.items() for c in cs}
+    for parent, cs in children.items():
+        (p0, p1), = ev[parent]
+        for c in cs:
+            (c0, c1), = ev[f"{parent}.{c}"]
+            assert p0 <= c0 <= c1 <= p1, (parent, c)

@@ -306,7 +306,9 @@ def test_one_registry_serves_gateway_engine_and_router(served):
     fams = set(reg.families())
     assert {"kotta_requests_total", "kotta_request_ttft_seconds",
             "kotta_engine_admitted_total", "kotta_routing_decisions_total",
-            "kotta_gateway_rounds_total", "kotta_slo_burn_rate"} <= fams
+            "kotta_gateway_rounds_total", "kotta_slo_burn_rate",
+            "kotta_engine_decode_sync_seconds_total",
+            "kotta_engine_queue_wait_seconds_total"} <= fams
     # Per-tenant labels: every tenant that submitted has its own series.
     seen = {t for t in ("tenant0", "tenant1", "tenant2")
             if reg.value("kotta_requests_total", tenant=t,
@@ -319,6 +321,11 @@ def test_one_registry_serves_gateway_engine_and_router(served):
         == rounds
     assert reg.value("kotta_engine_admitted_total", engine="e0") \
         == gw.metrics()["completed"] == len(trace)
+    # The engine's phase counters reach the registry as it runs.
+    assert reg.value("kotta_engine_decode_sync_seconds_total",
+                     engine="e0") > 0
+    assert reg.value("kotta_engine_queue_wait_seconds_total",
+                     engine="e0") > 0
 
 
 def test_gateway_exposition_round_trips(served):
