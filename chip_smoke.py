@@ -18,8 +18,8 @@ page_size 16, 8 decode slots, max_len 1024. Phases, in order:
 3. spec: a self-speculative engine (K=4) on the same prompts.
 4. int8: an int8-pool engine on the same prompts.
 5. kernels: each paged kernel against its jnp reference at these widths
-   (decode at 1 split and at the engine's split count, prefill, verify;
-   bf16 and int8).
+   (decode at 1 page a block and at the block the kernel chooses, prefill,
+   verify at 1 split and at the engine's split count; bf16 and int8).
 
 ``--four-chips`` runs only the replica phase: 4 unified replicas with
 affinity routing, replica i with its params (and so its pool and its
@@ -302,14 +302,20 @@ def check_kernels(cfg, *, slots: int, max_len: int, interpret: bool):
                     kv_dtype=kv_dtype)
         q, kp, vp, pt, k2, sc = _paged_inputs(key, b=slots, rows=(), **dims)
         lengths = jax.random.randint(k2, (slots,), 1, npages * ps + 1)
+        # The engine passes one row past the table for a slot whose budget
+        # ran out mid-chunk: here the first slot, before a live one, and
+        # the last.
+        lengths = lengths.at[0].set(npages * ps + 1).at[-1].set(
+            npages * ps + 1)
         with highest():
             ref = jax.jit(paged_decode_reference)(q, kp, vp, pt, lengths,
                                                   **sc)
-        for s in sorted({1, splits}):
-            out = jax.jit(lambda *a, s=s: flash_decode_fwd(
-                *a[:5], **a[5], num_splits=s, interpret=interpret))(
+        for ppb in (1, None):
+            out = jax.jit(lambda *a, ppb=ppb: flash_decode_fwd(
+                *a[:5], **a[5], pages_per_block=ppb, interpret=interpret))(
                 q, kp, vp, pt, lengths, sc)
-            compare(f"decode/{kv_dtype}/splits={s}", out, ref)
+            compare(f"decode/{kv_dtype}/pages_per_block={ppb or 'chosen'}",
+                    out, ref)
 
         q, kp, vp, pt, k2, sc = _paged_inputs(key, b=2, rows=(chunk,),
                                               **dims)

@@ -83,9 +83,9 @@ class ModelConfig:
     # earlier occurrence (sharper drafts on structured output, same greedy
     # tokens either way — verification restores exactness).
     spec_ngram: int = 2
-    # Batch-adaptive decode tuning (the BENCH_serve batch-32 droop):
-    # split-KV fills cores that idle when the decode batch is narrow, so the
-    # split count is chosen as ~decode_split_budget / slot_width, where
+    # Batch-adaptive decode tuning: the verify kernel's split-KV count is
+    # chosen as ~decode_split_budget / slot_width (the decode kernel has no
+    # split axis), where
     # slot_width is the dispatch's static batch dimension (max_slots — NOT
     # the live request count, which would retrace per occupancy level),
     # clamped to a divisor of the page-table width; the decode chunk length

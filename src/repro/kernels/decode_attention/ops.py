@@ -16,26 +16,28 @@ from .kernel import flash_decode_fwd
 from .ref import paged_decode_reference
 
 
-@partial(jax.jit, static_argnames=("num_splits", "interpret"))
+@partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
 def flash_decode(q, k_pages, v_pages, page_table, lengths, *,
                  k_scale=None, v_scale=None,
-                 num_splits: int = 1, interpret: bool = False):
+                 pages_per_block: int | None = None, interpret: bool = False):
     return flash_decode_fwd(q, k_pages, v_pages, page_table, lengths,
                             k_scale=k_scale, v_scale=v_scale,
-                            num_splits=num_splits, interpret=interpret)
+                            pages_per_block=pages_per_block,
+                            interpret=interpret)
 
 
 def default_num_splits(npages: int, target: int = 4, *, batch: int = 0,
                        split_budget: int = 0) -> int:
     """Largest split count <= target that divides the page-table width.
 
-    When ``batch`` and ``split_budget`` are given, the target adapts to
-    occupancy: split-KV exists to fill cores that idle when few slots are
-    active, but at high occupancy the (B, KV) grid axes already cover the
-    chip and extra splits only add partial-combine overhead (the batch-32
-    droop in BENCH_serve.json). Holding ``batch * splits`` near the budget
-    gives split counts of 32/8/1 at batch 1/4/32 for the default budget —
-    see ``ModelConfig.decode_split_budget``.
+    The split-KV verify kernel (``kernels/verify_attention``) takes its
+    split count from here; the decode kernel has no split axis. When
+    ``batch`` and ``split_budget`` are given, the target adapts to
+    occupancy: holding ``batch * splits`` near the budget gives split
+    counts of 32/8/1 at batch 1/4/32 for the default budget (see
+    ``ModelConfig.decode_split_budget``). Splits only help a chip whose
+    cores the (B, KV) grid leaves idle; a v5e has one TensorCore, where
+    splits run one after another and add a partial combine.
     """
     if split_budget and batch:
         target = max(1, split_budget // batch)
@@ -52,14 +54,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
 
     ``k_scale``/``v_scale``: per-row scale pages for an int8 pool; both
     backends dequantize with identical f32 arithmetic (kernel: per tile
-    load; reference: whole pool up front).
+    load; reference: whole pool up front). ``split_budget`` is accepted
+    for the verify kernel's sake and not used: the decode kernel has no
+    split axis.
     """
     if impl == "pallas" and jax.default_backend() == "tpu":
-        splits = default_num_splits(page_table.shape[1],
-                                    batch=page_table.shape[0],
-                                    split_budget=split_budget)
         return flash_decode_fwd(q, k_pages, v_pages, page_table, lengths,
-                                k_scale=k_scale, v_scale=v_scale,
-                                num_splits=splits)
+                                k_scale=k_scale, v_scale=v_scale)
     return paged_decode_reference(q, k_pages, v_pages, page_table, lengths,
                                   k_scale=k_scale, v_scale=v_scale)

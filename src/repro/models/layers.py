@@ -303,7 +303,7 @@ def paged_attention_block(cfg, p, x, *, pool, page_table, pos):
     scattered (quantized, for an int8 pool) into the pool page
     ``page_table[b, pos // ps]`` at offset ``pos % ps``.
 
-    ``attn_impl="pallas"`` dispatches the split-KV flash-decode kernel on TPU
+    ``attn_impl="pallas"`` dispatches the block-streaming decode kernel on TPU
     (see kernels/decode_attention); other impls use the fused-gather oracle.
     Both dequantize int8 tiles with identical f32 arithmetic.
     """

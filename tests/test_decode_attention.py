@@ -47,19 +47,21 @@ def test_flash_decode_sweep(b, h, kv, hd, dtype):
     ps, npages = 8, 4
     q, kp, vp, pt, lengths = _paged_case(
         jax.random.PRNGKey(0), b, h, kv, hd, ps, npages, 32, dtype)
-    out = flash_decode(q, kp, vp, pt, lengths, num_splits=2, interpret=True)
+    out = flash_decode(q, kp, vp, pt, lengths, pages_per_block=2,
+                       interpret=True)
     ref = paged_decode_reference(q, kp, vp, pt, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("num_splits", [1, 2, 4])
-def test_flash_decode_split_kv(num_splits):
-    """Split-KV partial combine is exact for any split factor."""
+@pytest.mark.parametrize("pages_per_block", [1, 2, 4])
+def test_flash_decode_pages_per_block(pages_per_block):
+    """The online softmax across blocks is exact for a block of one page,
+    of several, and of the whole table."""
     q, kp, vp, pt, lengths = _paged_case(
         jax.random.PRNGKey(1), 2, 8, 2, 32, 8, 4, 16, jnp.float32)
-    out = flash_decode(q, kp, vp, pt, lengths, num_splits=num_splits,
-                       interpret=True)
+    out = flash_decode(q, kp, vp, pt, lengths,
+                       pages_per_block=pages_per_block, interpret=True)
     ref = paged_decode_reference(q, kp, vp, pt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-3, atol=1e-3)
@@ -74,7 +76,8 @@ def test_flash_decode_ragged_lengths():
     vp = jax.random.normal(ks[2], (kv, b * npages, ps, hd))
     pt = jnp.arange(b * npages, dtype=jnp.int32).reshape(b, npages)
     lengths = jnp.array([1, 5, 17, npages * ps], jnp.int32)
-    out = flash_decode(q, kp, vp, pt, lengths, num_splits=2, interpret=True)
+    out = flash_decode(q, kp, vp, pt, lengths, pages_per_block=2,
+                       interpret=True)
     ref = paged_decode_reference(q, kp, vp, pt, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-3, atol=1e-3)
@@ -96,7 +99,7 @@ def test_flash_decode_int8_parity(b, h, kv, hd):
         jax.random.PRNGKey(5), b, h, kv, hd, ps, npages, 32, jnp.float32)
     qp = quantize_pool({"k": kp, "v": vp})
     scales = dict(k_scale=qp["k_scale"], v_scale=qp["v_scale"])
-    out = flash_decode(q, qp["k"], qp["v"], pt, lengths, num_splits=2,
+    out = flash_decode(q, qp["k"], qp["v"], pt, lengths, pages_per_block=2,
                        interpret=True, **scales)
     ref = paged_decode_reference(q, qp["k"], qp["v"], pt, lengths, **scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -106,9 +109,99 @@ def test_flash_decode_int8_parity(b, h, kv, hd):
                                rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("kv,g", [(4, 8), (8, 2)])     # yi-6b's, internlm2's
+@pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+def test_flash_decode_block_boundaries(kv, g, pool):
+    """Lengths at every edge of a 4-page block of 8-row pages: one row,
+    mid-page, mid-block, exactly on a block boundary, the whole table,
+    and an idle slot (an all-zero page-table row, length 1)."""
+    ps, npages, hd = 8, 8, 64
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    lengths = jnp.array([1, 12, 32, 43, npages * ps, 1], jnp.int32)
+    b = lengths.shape[0]
+    q, kp, vp, pt, _ = _paged_case(jax.random.PRNGKey(6), b, kv * g, kv,
+                                   hd, ps, npages, b * npages + 1, dtype)
+    pt = pt.at[-1].set(0)
+    scales = {}
+    if pool == "int8":
+        qp = quantize_pool({"k": kp, "v": vp})
+        kp, vp = qp["k"], qp["v"]
+        scales = dict(k_scale=qp["k_scale"], v_scale=qp["v_scale"])
+    out = flash_decode(q, kp, vp, pt, lengths, pages_per_block=4,
+                       interpret=True, **scales)
+    ref = paged_decode_reference(q, kp, vp, pt, lengths, **scales)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("lengths", [
+    (65, 40),          # a slot one row past its table, then a live one
+    (40, 23, 65),      # the slot past its table is the last
+    (17, 0, 0, 40),    # zero-length slots between live ones
+    (0, 65, 0, 9),
+])
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+def test_flash_decode_lengths_off_the_table(lengths, pool):
+    """The engine passes ``length = npages * ps + 1`` for a slot whose budget
+    ran out mid-chunk, and length 0 for no slot it builds but nothing
+    forbids. Neither may fetch another slot's pages or leave a later
+    slot's block unfetched: the next slot's several pages must come out
+    right. A zero-length slot writes zeros. The TPU interpreter then runs
+    the kernel's DMAs and semaphores as the chip would, and raises on a
+    read past the page table."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.decode_attention.kernel import flash_decode_fwd
+    ps, npages, hd, kv, g = 8, 8, 32, 2, 4
+    lengths = jnp.array(lengths, jnp.int32)
+    b = lengths.shape[0]
+    q, kp, vp, pt, _ = _paged_case(jax.random.PRNGKey(7), b, kv * g, kv,
+                                   hd, ps, npages, b * npages, jnp.float32)
+    scales = {}
+    if pool == "int8":
+        qp = quantize_pool({"k": kp, "v": vp})
+        kp, vp = qp["k"], qp["v"]
+        scales = dict(k_scale=qp["k_scale"], v_scale=qp["v_scale"])
+    ref = np.asarray(paged_decode_reference(q, kp, vp, pt, lengths,
+                                            **scales))
+    live = np.asarray(lengths) > 0
+    for interpret in (True, pltpu.InterpretParams()):
+        out = np.asarray(flash_decode_fwd(q, kp, vp, pt, lengths,
+                                          pages_per_block=4,
+                                          interpret=interpret, **scales))
+        np.testing.assert_allclose(out[live], ref[live], rtol=1e-3,
+                                   atol=1e-3)
+        np.testing.assert_array_equal(out[~live], 0)
+
+
+@pytest.mark.parametrize("kv,quantized,expected", [
+    (8, False, 32),     # internlm2.long_decode: bf16 pool
+    (4, False, 48),     # yi6b.*: bf16 pool
+    (8, True, 24),      # int8 pool: 32-row page tiles + scale rows
+    (4, True, 48),
+])
+def test_choose_block_pages(kv, quantized, expected):
+    """The block is the largest divisor of the table width whose
+    double-buffered K/V (and scale) blocks fit the VMEM budget."""
+    from repro.kernels.decode_attention.kernel import (VMEM_BUDGET,
+                                                       choose_block_pages)
+    npages, ps, hd = 96, 16, 128          # 1536 rows a slot, page 16
+    itemsize = 1 if quantized else 2
+    ppb = choose_block_pages(npages, kv, ps, hd, itemsize,
+                             quantized=quantized)
+    assert ppb == expected
+    assert npages % ppb == 0
+    # A page in VMEM: 16 bf16 rows fill one tile, 16 int8 rows half of one.
+    page = 32 * hd + (8 * 4 * ps if quantized else 0)
+    footprint = lambda d: 2 * 2 * kv * d * page
+    assert footprint(ppb) <= VMEM_BUDGET
+    bigger = [d for d in range(ppb + 1, npages + 1) if npages % d == 0]
+    assert all(footprint(d) > VMEM_BUDGET for d in bigger)
+
+
 def test_default_num_splits_occupancy_adaptive():
-    """Split-KV fills idle cores at low occupancy; at high occupancy the
-    batch axis already covers the chip (batch * splits ~ budget)."""
+    """The verify kernel's split count: more splits at low occupancy,
+    fewer at high (batch * splits ~ budget)."""
     from repro.kernels.decode_attention.ops import default_num_splits
     assert default_num_splits(8, batch=1, split_budget=32) == 8
     assert default_num_splits(8, batch=8, split_budget=32) == 4
