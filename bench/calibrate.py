@@ -41,6 +41,7 @@ def _setup(cell, conf, spec, seed, seconds):
 def seeds(cell, conf, spec, limits, seed_list, seconds):
     from . import check, model
     from .drive import Driver
+    reference = model.architecture(conf).Reference
     for seed in seed_list:
         t0 = time.perf_counter()
         engine, traffic = _setup(cell, conf, spec, seed, seconds)
@@ -52,7 +53,8 @@ def seeds(cell, conf, spec, limits, seed_list, seconds):
                             limits["sample_requests"])
         t1 = time.perf_counter()
         got = check.compare(conf["model"], seed, rec, rids, control=True,
-                            pad_rows=limits["sample_requests"])
+                            pad_rows=limits["sample_requests"],
+                            reference=reference)
         got["compare_s"] = time.perf_counter() - t1
         got.update(seed=seed, finished=len(rec.done), seconds=
                    time.perf_counter() - t0)
@@ -60,11 +62,13 @@ def seeds(cell, conf, spec, limits, seed_list, seconds):
 
 
 def rates(cell, conf, spec, rate_list, seconds, seed):
+    from . import model
     from .drive import Driver
     from .readers import p95
     from .run import Run
     from .readers import tpot_ms, ttft_ms
     from .traffic import generate
+    arch = model.architecture(conf)
     engine, _ = _setup(cell, conf, spec, seed, seconds)
     for rate in rate_list:
         s = dict(spec, rate_per_s=rate)
@@ -74,8 +78,8 @@ def rates(cell, conf, spec, rate_list, seconds, seed):
         backlog = engine.queued
         d.drain()
         engine.abort()              # what the drain's limit left
-        run = Run(cell=cell, conf=conf, m=conf["model"], traffic=traffic,
-                  rec=rec, peaks={}, trace=None, setup_s=0.0,
+        run = Run(cell=cell, conf=conf, m=conf["model"], arch=arch,
+                  traffic=traffic, rec=rec, peaks={}, trace=None, setup_s=0.0,
                   prefill_chunk=engine.prefill_chunk)
         ttft = ttft_ms(run)
         half = len(ttft) // 2

@@ -2,16 +2,22 @@
 
 After the window has closed and the program's state is freed, a sample of
 the requests the engine finished, drawn from the run seed and holding the
-longest of them, is run through the reference (:mod:`bench.reference`):
-each prompt followed by the tokens it was served, once. For every served
-token the gap by which the reference's logit for it lies below the
-reference's best logit at that position is read, and the widest gap is
-held to the cell's limit (``bench/checks/<cell>.json``). Greedy decoding
-serves the argmax, so a sound program reads a gap of rounding size only.
+longest of them, is run through the reference of the configuration's
+architecture (``Reference`` of ``bench/architectures/<name>.py``; for the
+dense decoder, :mod:`bench.reference`): each prompt followed by the tokens
+it was served, once. For every served token the gap by which the
+reference's logit for it lies below the reference's best logit at that
+position is read, and the widest gap is held to the cell's limit
+(``bench/checks/<cell>.json``). Greedy decoding serves the argmax, so a
+sound program reads a gap of rounding size only.
 
 The control puts the reference in float8 in the program's place: at each
 position of the same prompts and served tokens, the token it ranks first
 is read in the float32 reference the same way.
+
+A new architecture brings its reference as a file of its own, with its
+``fp8`` control; this comparison, and the limits of
+``bench/checks/<cell>.json``, stay as they are.
 """
 from __future__ import annotations
 
@@ -90,24 +96,25 @@ def _gaps(logits, chosen):
 
 
 def compare(m: dict, seed: int, rec, rids, *, pad_rows: int = 1,
-            control: bool = False) -> dict:
+            control: bool = False, reference=Reference) -> dict:
     """Widest gap of the served tokens (and, with ``control``, of the
-    float8 reference's first choices) below the reference's best logit,
-    the sequences padded to ``pad_rows``. Returns the readings with the number
-    of tokens compared."""
+    float8 reference's first choices) below the best logit of
+    ``reference`` (the architecture's class), the sequences padded to
+    ``pad_rows``. Returns the readings with the number of tokens
+    compared."""
     t0 = time.perf_counter()
     tokens, wanted = _batch(rec, rids, pad_rows)
     vocab = m["vocab_size"]
     served_all = np.concatenate([np.asarray(t) for _, t in wanted])
     out_of_vocab = int(((served_all < 0) | (served_all >= vocab)).sum())
-    ref = Reference(m, seed)
+    ref = reference(m, seed)
     rows, served = _rows(ref.final_hidden(tokens), wanted)
     rows.block_until_ready()
     t1 = time.perf_counter()
     served = np.clip(served, 0, vocab - 1)
     ctrl_rows = None
     if control:
-        ctl = Reference(m, seed, fp8=True)
+        ctl = reference(m, seed, fp8=True)
         ctrl_rows, _ = _rows(ctl.final_hidden(tokens), wanted)
         ctrl_u = ctl.unembed()
     u = ref.unembed()

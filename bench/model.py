@@ -3,11 +3,15 @@
 A configuration is ``bench/configs/<name>.json``: ``model`` holds the
 published sizes as they are run, ``serving`` the engine's geometry and
 dtypes. The program's own config registry entry (``registry``) is
-replaced by both, key by key, so the file is what runs.
+replaced by both, key by key, so the file is what runs. Its
+``architecture`` (``dense_gqa`` where the file names none) is the module
+``bench/architectures/<architecture>.py`` that holds the reference, the
+weight rules and the operation counts of that kind of model.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import math
 from pathlib import Path
@@ -19,11 +23,41 @@ import numpy as np
 from . import weights
 
 CONFIGS_DIR = Path(__file__).resolve().parent / "configs"
+ARCHITECTURES_DIR = Path(__file__).resolve().parent / "architectures"
+DEFAULT_ARCHITECTURE = "dense_gqa"
+# What an architecture module provides (bench/architectures/dense_gqa.py).
+ARCHITECTURE_NAMES = ("Reference", "LEAVES", "decode_attention_call",
+                      "prefill_attention_call", "decode_step_flops",
+                      "prefill_step_flops")
 ENGINE_KEYS = ("max_len",)          # serving keys that are engine arguments
 
 
 def load_config(name: str, directory: Path = CONFIGS_DIR) -> dict:
     return json.loads((directory / f"{name}.json").read_text())
+
+
+def load_architecture(name: str, directory: Path = ARCHITECTURES_DIR):
+    """The module ``<directory>/<name>.py``; raises, naming what is
+    missing, where there is no such file or it lacks one of
+    ``ARCHITECTURE_NAMES``."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"architecture {name!r}: no module {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_architecture_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in ARCHITECTURE_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"architecture {name!r} ({path}) lacks "
+                             f"{', '.join(missing)}")
+    return mod
+
+
+def architecture(conf: dict, directory: Path = ARCHITECTURES_DIR):
+    """The architecture module the configuration names."""
+    return load_architecture(conf.get("architecture", DEFAULT_ARCHITECTURE),
+                             directory)
 
 
 def model_config(conf: dict):
@@ -74,8 +108,12 @@ def fit_check(cfg, slots: int, max_len: int, bytes_limit) -> dict:
     return out
 
 
-def draw_params(conf: dict, cfg, seed: int):
-    params = weights.program_params(conf["model"], abstract_params(cfg), seed)
+def draw_params(conf: dict, cfg, seed: int, arch=None):
+    """The program's weights by the rules of ``arch`` (by default the
+    architecture the configuration names)."""
+    arch = arch or architecture(conf)
+    params = weights.program_params(conf["model"], abstract_params(cfg), seed,
+                                    arch.LEAVES)
     return jax.block_until_ready(params)
 
 

@@ -4,8 +4,13 @@ A reader is ``bench/metrics/<metric name>.py`` with ``read(run)``, which
 returns the metric's value in its unit, or None where the run holds
 nothing to read (the harness then leaves the metric out). ``run`` is the
 harness's :class:`bench.run.Run`: the driver's record, the traffic, the
-configuration's ``model`` sizes, the device's peaks and, in a traced run,
-the trace :class:`bench.trace.Summary`.
+configuration's ``model`` sizes and architecture module, the device's
+peaks and, in a traced run, the trace :class:`bench.trace.Summary`. The
+operations and bytes of kernel calls and model steps come from the
+architecture (``decode_attention_call``, ``prefill_attention_call``,
+``decode_step_flops``, ``prefill_step_flops``; the dense decoder's are
+:mod:`bench.counts`), so a new architecture's readers reuse the arithmetic
+below with counts that fit its layers.
 
 Kernel and program names are matched as they appear in the device trace.
 """
@@ -98,16 +103,16 @@ def prefix_hit_rate(run):
 
 def decode_work(run):
     """(kernel Work, model flops) of every decode step in the window."""
-    m, peaks = run.m, run.peaks
+    m, peaks, arch = run.m, run.peaks, run.arch
     work, flops = counts.Work(), 0.0
     for c in window_chunks(run):
         for i in range(c.steps):
             lengths = [pos + i + 1 for pos, budget in c.live if i < budget]
             if not lengths:
                 continue
-            f, b = counts.decode_attention_call(m, lengths)
+            f, b = arch.decode_attention_call(m, lengths)
             work.add(f, b, peaks, calls=m["num_layers"])
-            flops += counts.decode_step_flops(m, lengths)
+            flops += arch.decode_step_flops(m, lengths)
     return work, flops
 
 
@@ -115,7 +120,7 @@ def prefill_work(run):
     """(kernel Work, model flops) of every prefill step in the window. A
     wave whose rows (from the prefix found before it) hold more new tokens
     than the engine counted is scaled down to the engine's count."""
-    m, peaks = run.m, run.peaks
+    m, peaks, arch = run.m, run.peaks, run.arch
     work, flops = counts.Work(), 0.0
     for w in window_waves(run):
         steps = prefill_rows(w, run.prefill_chunk)
@@ -123,10 +128,10 @@ def prefill_work(run):
         scale = min(1.0, w.prefill_tokens / est) if est else 0.0
         wave = counts.Work()
         for rows in steps:
-            f, b = counts.prefill_attention_call(m, [(s, n) for s, n, _ in
-                                                     rows])
+            f, b = arch.prefill_attention_call(m, [(s, n) for s, n, _ in
+                                                   rows])
             wave.add(f, b, peaks, calls=m["num_layers"])
-            flops += scale * counts.prefill_step_flops(m, rows)
+            flops += scale * arch.prefill_step_flops(m, rows)
         work.flops += scale * wave.flops
         work.bytes += scale * wave.bytes
         work.bound_s += scale * wave.bound_s

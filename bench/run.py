@@ -3,10 +3,16 @@
     python3 -m bench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Reads the cell from ``BENCHMARK.json`` and finds by name its configuration
-(``bench/configs/<config>.json``), its traffic mix
-(``bench/traffic/<traffic>.json``), the limits of its check
-(``bench/checks/<cell>.json``) and one reader per metric
-(``bench/metrics/<metric>.py``); adding a cell adds files and edits none.
+(``bench/configs/<config>.json``), the architecture that configuration
+names (``bench/architectures/<architecture>.py``, ``dense_gqa`` where it
+names none: the reference, the weight rules and the operation counts of
+that kind of model), its traffic mix (``bench/traffic/<traffic>.json``),
+the limits of its check (``bench/checks/<cell>.json``) and one reader per
+metric (``bench/metrics/<metric>.py``). A cell of a new architecture adds
+its configuration file, its architecture module, its traffic, its check
+limits and any metric readers of its own; adding a cell adds files and
+edits none. An architecture that is named but missing, or lacks one of the
+names the harness takes from it, fails at set-up.
 
 Set-up, from process start to the window's opening: the fit check, the
 weights drawn on the device from the seed, the engine and its pool, and a
@@ -56,6 +62,7 @@ class Run:
     cell: dict
     conf: dict
     m: dict
+    arch: object                 # the configuration's architecture module
     traffic: object
     rec: object
     peaks: dict
@@ -119,12 +126,13 @@ def _listen():
 def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
              trace: bool, *, require_chip: bool = True,
              conf: dict | None = None, spec: dict | None = None,
-             limits: dict | None = None, t_start: float = T_PROCESS,
-             cache_dir: Path | None = None, breakage=None) -> dict:
+             limits: dict | None = None, arch=None,
+             t_start: float = T_PROCESS, cache_dir: Path | None = None,
+             breakage=None) -> dict:
     """One run of ``cell_name``; returns the result object. Compiled
-    programs are kept in ``cache_dir``. ``conf``, ``spec`` and ``limits``
-    replace the files found by name, and ``breakage(engine)`` may break
-    the engine after set-up (tests)."""
+    programs are kept in ``cache_dir``. ``conf``, ``spec``, ``limits`` and
+    ``arch`` replace the files found by name, and ``breakage(engine)`` may
+    break the engine after set-up (tests)."""
     import jax
     from . import check, model, trace as tracing
     from .drive import Driver
@@ -148,6 +156,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     counter = _listen()
 
     conf = conf or model.load_config(cell["config"])
+    arch = arch or model.architecture(conf)
     spec = spec or load_spec(cell["traffic"])
     limits = limits or check.load_limits(cell_name)
     peaks = load_peaks(dev.device_kind) if require_chip else \
@@ -158,7 +167,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     fit = model.fit_check(cfg, slots, max_len, limit_bytes)
     log(f"info fit: decode step needs {fit['need']} B (temp {fit['temp']}) "
         f"of {limit_bytes} B")
-    params = model.draw_params(conf, cfg, seed)
+    params = model.draw_params(conf, cfg, seed, arch)
     engine = model.build_engine(conf, cfg, params)
     del params
     traffic = generate(spec, seed, seconds, cfg.vocab_size)
@@ -186,9 +195,9 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
         summary = tracing.load(trace_dir, devices=cell["chips"])
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    run = Run(cell=cell, conf=conf, m=conf["model"], traffic=traffic,
-              rec=rec, peaks=peaks, trace=summary, setup_s=setup_s,
-              prefill_chunk=engine.prefill_chunk)
+    run = Run(cell=cell, conf=conf, m=conf["model"], arch=arch,
+              traffic=traffic, rec=rec, peaks=peaks, trace=summary,
+              setup_s=setup_s, prefill_chunk=engine.prefill_chunk)
     metrics = {}
     for mdef in metrics_for(bench, cell, trace):
         value = load_reader(mdef["name"])(run)
@@ -231,7 +240,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
                         limits["sample_requests"])
     t_ref = time.perf_counter()
     got = check.compare(conf["model"], seed, rec, rids,
-                        pad_rows=limits["sample_requests"]) if rids else \
+                        pad_rows=limits["sample_requests"],
+                        reference=arch.Reference) if rids else \
         {"max_logit_gap": None, "tokens_compared": 0,
          "requests_compared": 0, "out_of_vocab": 0}
     log(f"info reference: {got['requests_compared']} requests, "

@@ -132,9 +132,11 @@ def test_a_layer_drawn_alone_equals_its_slice_of_the_tree():
 
 # -- the comparison that decides `correct` ---------------------------------------------------
 
-def _tiny_run(cell, seed=BIG_SEED, seconds=1.5, breakage=None, **over):
+def _tiny_run(cell, seed=BIG_SEED, seconds=1.5, breakage=None, arch=None,
+              **over):
     """A run of ``cell`` (or of the mix of that name, for a mix that has
-    files but no cell yet) at the tiny size on the CPU."""
+    files but no cell yet) at the tiny size on the CPU, with the
+    architecture module ``arch`` in place of the one found by name."""
     bench = run.load_benchmark()
     if not any(c["name"] == cell for c in bench["workloads"]):
         bench["workloads"].append({"name": cell, "config": "yi-6b",
@@ -145,7 +147,7 @@ def _tiny_run(cell, seed=BIG_SEED, seconds=1.5, breakage=None, **over):
     return run.run_cell(bench, cell, seed, seconds, False,
                         require_chip=False, conf=tiny.conf(),
                         spec=tiny.spec(mix, **over), limits=tiny.LIMITS,
-                        breakage=breakage)
+                        arch=arch, breakage=breakage)
 
 
 @pytest.mark.parametrize("cell", tiny.CELLS)

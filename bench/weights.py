@@ -7,6 +7,12 @@ serves, and the reference draws any one layer again by itself, so it never
 takes an array the program has held. Matrices are uniform with standard
 deviation 1/sqrt(fan-in) (the embedding 1); norm weights are uniform in
 [0.5, 1.5], so that a norm applied wrongly shows.
+
+Each leaf path has a rule in the table of its configuration's architecture
+(``LEAVES`` of ``bench/architectures/<name>.py``): the size key of ``m``
+that is its fan-in, a function of ``m`` that gives the fan-in, or one of
+the kinds "norm", "embedding" and "heads_x_head_dim". The table below is
+the dense decoder's, and the default.
 """
 from __future__ import annotations
 
@@ -17,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Leaf path -> the model size its fan-in is, or "norm", or "embedding".
+# Leaf path -> the model size its fan-in is, or "norm", or "embedding"
+# (the dense GQA decoder's leaves).
 LEAVES = {
     "embedding": "embedding",
     "unembed": "d_model",
@@ -50,14 +57,18 @@ def _leaf_key(key, path: str):
     return jax.random.fold_in(key, np.uint32(zlib.crc32(path.encode())))
 
 
-def _scale(m: dict, path: str) -> tuple[str, float]:
-    kind = LEAVES.get(path)
-    if kind is None:
+def _scale(m: dict, path: str, leaves: dict) -> tuple[str, float]:
+    rule = leaves.get(path)
+    if rule is None:
         raise KeyError(f"no weight rule for leaf {path!r}")
-    if kind in ("norm", "embedding"):
-        return kind, 1.0
-    fan_in = m["num_heads"] * m["head_dim"] if kind == "heads_x_head_dim" \
-        else m[kind]
+    if callable(rule):
+        fan_in = rule(m)
+    elif rule in ("norm", "embedding"):
+        return rule, 1.0
+    elif rule == "heads_x_head_dim":
+        fan_in = m["num_heads"] * m["head_dim"]
+    else:
+        fan_in = m[rule]
     return "matrix", fan_in ** -0.5
 
 
@@ -68,27 +79,30 @@ def _draw(key, shape, kind: str, std: float, dtype):
     return (u * (std * math.sqrt(3.0))).astype(dtype)
 
 
-def draw_layer_leaf(m: dict, key, path: str, layer: int, shape, dtype):
+def draw_layer_leaf(m: dict, key, path: str, layer: int, shape, dtype,
+                    leaves: dict = LEAVES):
     """Layer ``layer`` of a stacked leaf (``shape`` without the layer
-    axis), or a whole unstacked leaf when ``layer`` is None."""
-    kind, std = _scale(m, path)
+    axis), or a whole unstacked leaf when ``layer`` is None, by the rule
+    ``leaves`` gives its path."""
+    kind, std = _scale(m, path, leaves)
     k = _leaf_key(key, path)
     if layer is not None:
         k = jax.random.fold_in(k, jnp.asarray(layer, jnp.uint32))
     return _draw(k, tuple(shape), kind, std, dtype)
 
 
-def program_params(m: dict, abstract_tree, seed: int):
+def program_params(m: dict, abstract_tree, seed: int, leaves: dict = LEAVES):
     """The program's whole parameter tree, drawn on the default device in
-    one jitted call. ``abstract_tree`` gives each leaf's shape and dtype;
-    leaves under ``layers`` carry a leading layer axis."""
+    one jitted call by the rules of ``leaves``. ``abstract_tree`` gives
+    each leaf's shape and dtype; leaves under ``layers`` carry a leading
+    layer axis."""
 
     def build(key):
         def go(node, path):
             if isinstance(node, dict):
                 return {k: go(v, f"{path}/{k}" if path else k)
                         for k, v in node.items()}
-            kind, std = _scale(m, path)
+            kind, std = _scale(m, path, leaves)
             k = _leaf_key(key, path)
             if path.startswith("layers/"):
                 keys = jax.vmap(lambda i: jax.random.fold_in(k, i))(
